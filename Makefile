@@ -5,8 +5,8 @@ export PYTHONPATH := src$(if $(PYTHONPATH),:$(PYTHONPATH),)
 
 .PHONY: verify test coverage lint bench-mixing bench-wire bench-rounds bench-lm-rounds bench-serve bench quickstart install sweep-smoke sweep-paper sweep-churn-smoke sweep-lm-smoke
 
-verify:  ## tier-1 test suite (the CI gate)
-	$(PY) -m pytest -x -q
+verify:  ## tier-1 test suite (the CI gate); CPU only, kernels in interpret mode
+	JAX_PLATFORMS=cpu $(PY) -m pytest -x -q
 
 lint:  ## ruff baseline (when installed) + repro.lint repo rules
 	@if $(PY) -c "import ruff" >/dev/null 2>&1; then \

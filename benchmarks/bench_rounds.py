@@ -257,6 +257,9 @@ def bench_faulted(n: int, rounds: int, ds) -> dict:
 def bench_sharded() -> dict:
     """The sparse_sharded row, via a subprocess with an 8-device mesh."""
     env = dict(os.environ)
+    # Fake CPU devices, and never the accelerator: this parent may already
+    # hold the chip, which a second process cannot open.
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (
         env.get("XLA_FLAGS", "")
         + f" --xla_force_host_platform_device_count={SHARDED_SHARDS}"

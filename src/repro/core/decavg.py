@@ -77,23 +77,6 @@ __all__ = [
 PyTree = Any
 
 
-def _shard_map(f, *, mesh, in_specs, out_specs, axis_names=None):
-    """jax.shard_map across jax versions (experimental home before 0.5).
-
-    ``axis_names`` (the manual axes) maps to the experimental API's ``auto``
-    complement when running on older jax.
-    """
-    if hasattr(jax, "shard_map"):
-        kw = {} if axis_names is None else {"axis_names": axis_names}
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map  # jax < 0.5
-
-    kw = {}
-    if axis_names is not None and set(axis_names) != set(mesh.axis_names):
-        kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
 def _mix_leaf(w: jax.Array, leaf: jax.Array) -> jax.Array:
     """(N,N) x (N, ...) contraction over the node axis, f32 accumulation.
 
@@ -185,7 +168,7 @@ def mix_sharded(
 
     def mix_one(leaf: jax.Array) -> jax.Array:
         spec = P(axes, *([None] * (leaf.ndim - 1)))
-        return _shard_map(
+        return jax.shard_map(
             functools.partial(body),
             mesh=mesh,
             in_specs=(P(), spec),
@@ -379,7 +362,7 @@ def mix_sharded_sparse(
 
     def mix_cat(cat: jax.Array) -> jax.Array:
         spec = P(axes, None)
-        return _shard_map(
+        return jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(P(), P(), P(), P(), P(), P(), P(), P(), spec),
@@ -503,7 +486,7 @@ def mix_sharded_sparse_faulted(
 
     def mix_cat2(cat: jax.Array, pcat: jax.Array) -> jax.Array:
         spec = P(axes, None)
-        return _shard_map(
+        return jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(P(),) * 10 + (spec, spec),
@@ -561,7 +544,7 @@ def mix_permute(
 
     def mix_one(leaf: jax.Array) -> jax.Array:
         spec = P(node_axis, *([None] * (leaf.ndim - 1)))
-        return _shard_map(
+        return jax.shard_map(
             body,
             mesh=mesh,
             in_specs=spec,
@@ -1173,7 +1156,14 @@ class GossipEngine:
         # Period-constant derived layouts, built lazily on first use.
         self._ell = None  # scalar ELL view of _csr
         self._bell = None  # blocked ELL view of _csr
-        self._shcsr = None  # sharded-CSR view of _csr
+        # The sharded-CSR view is staged here, outside any trace: the loop
+        # path's jitted round reads it through sharded_csr(), and one built
+        # lazily inside that trace would cache tracers past its end.
+        self._shcsr = (
+            sparse.shard_csr(self._csr, self.mesh.shape[self.node_axis])
+            if self.backend == "sparse_sharded"
+            else None
+        )
         self._colors = (
             self._coloring_for(period, g) if self.backend == "permute" else None
         )

@@ -489,18 +489,18 @@ class BlockELL:
     Rows are grouped into blocks of ``block`` (the f32 sublane count); for
     each destination block the distinct *source blocks* touched by any of its
     rows are enumerated, and the weights coupling the two blocks are stored
-    as a dense (block, block) tile. One kernel grid step is then a single
-    aligned DMA of the source block's P rows plus a (block, block) @
-    (block, bd) mini-matmul — real sublane packing instead of the scalar
-    kernel's (1, bd) row-at-a-time gathers.
+    as a dense (block, block) tile. Each tile is applied as one aligned DMA
+    of the source block's P rows plus a (block, block) @ (block, bd)
+    mini-matmul — real sublane packing instead of the scalar kernel's
+    (1, bd) row-at-a-time gathers.
 
     Attributes:
       idx: (NB, KB) int32 — source block ids per destination block, padded
            with 0 (their weight tiles are all-zero).
       val: (NB*block, KB*block) f32 — ``val[r, t*block + o]`` is the weight
            of global row r against row ``idx[r//block, t]*block + o``. KB is
-           padded so the trailing dim is a multiple of ``block * lane_pad``
-           (TPU lane alignment of the (block, block) tile stream).
+           padded to a multiple of the kernel's tiles per grid step (16), so
+           each step reads one full (8, 128) weight block.
       n:   unpadded row count; block: rows per block.
     """
 
@@ -518,13 +518,15 @@ class BlockELL:
         return int(self.idx.shape[1])
 
 
-def block_ell_from_csr(csr: CSR, *, block: int = 8, lane_pad: int = 16) -> BlockELL:
+def block_ell_from_csr(csr: CSR, *, block: int = 8) -> BlockELL:
     """Build the 8-row-blocked ELL layout (see BlockELL) from a CSR matrix.
 
-    ``lane_pad`` rounds the per-block source count up so the stacked weight
-    tiles' trailing dim (KB * block) is a multiple of block * lane_pad = 128
-    lanes for the default block=8.
+    The per-block source count is rounded up to a multiple of the kernel's
+    ``TILES_PER_STEP``, so the stacked weight tiles' trailing dim is a
+    multiple of its (8, 128) weight block.
     """
+    from repro.kernels.sparse_gossip import TILES_PER_STEP
+
     n = csr.shape[0]
     nb = -(-n // block)
     ptr = np.asarray(csr.indptr)
@@ -545,7 +547,7 @@ def block_ell_from_csr(csr: CSR, *, block: int = 8, lane_pad: int = 16) -> Block
         entries.append(ent)
 
     kb = max(max((len(s) for s in slots), default=0), 1)
-    kb = -(-kb // lane_pad) * lane_pad
+    kb = -(-kb // TILES_PER_STEP) * TILES_PER_STEP
     idx = np.zeros((nb, kb), dtype=np.int32)
     val = np.zeros((nb * block, kb * block), dtype=np.float32)
     for b, (slot, ent) in enumerate(zip(slots, entries)):
@@ -557,7 +559,7 @@ def block_ell_from_csr(csr: CSR, *, block: int = 8, lane_pad: int = 16) -> Block
 
 
 def stack_block_ell(
-    csrs: list[CSR], *, block: int = 8, lane_pad: int = 16
+    csrs: list[CSR], *, block: int = 8
 ) -> tuple[np.ndarray, np.ndarray]:
     """Blocked-ELL layouts for every schedule period, padded to a common
     block count and stacked on a leading period axis.
@@ -572,7 +574,7 @@ def stack_block_ell(
         raise ValueError("need at least one period")
     if any(c.shape != csrs[0].shape for c in csrs):
         raise ValueError("all periods must share the matrix shape")
-    bells = [block_ell_from_csr(c, block=block, lane_pad=lane_pad) for c in csrs]
+    bells = [block_ell_from_csr(c, block=block) for c in csrs]
     kb = max(b.max_blocks_per_row for b in bells)  # lane-aligned per period
     idx = np.stack(
         [np.pad(b.idx, ((0, 0), (0, kb - b.idx.shape[1]))) for b in bells]
@@ -643,7 +645,8 @@ def mix_sparse_pallas(
     Two kernels (kernels/sparse_gossip.py), selected by ``blocked``:
 
     - blocked (default on real TPU): 8-row-blocked ELL — sublane-packed
-      (8, bd) source-block DMAs + (8, 8) weight-tile mini-matmuls.
+      (8, bd) source-block DMAs + (8, 8) weight-tile mini-matmuls, 16 tiles
+      per grid step.
     - scalar (default under interpret, i.e. off-TPU): the per-row (1, bd)
       gather kernel; far fewer grid steps through the slow interpreter.
 

@@ -37,7 +37,10 @@ import numpy as np
 from repro.experiments.spec import ExperimentSpec
 from repro.experiments.store import ResultsStore
 
-__all__ = ["run_spec", "run_sweep", "build_partition", "default_class_groups"]
+__all__ = [
+    "run_spec", "run_sweep", "build_mlp_trainer", "build_partition",
+    "default_class_groups",
+]
 
 Emit = Callable[[dict[str, Any]], None]
 
@@ -134,11 +137,18 @@ def _graph_records(engine, rounds: int) -> dict[str, Any]:
     return out
 
 
-def _run_mlp(spec: ExperimentSpec, emit: Emit, verbose: bool) -> dict[str, Any]:
+def build_mlp_trainer(spec: ExperimentSpec):
+    """The data, partition and ``DecentralizedTrainer`` an mlp spec runs.
+
+    Returns ``(trainer, ds, holds_g2)``: the trainer at round 0, the
+    synthetic dataset, and the per-node mask of nodes holding any G2 data.
+    ``_run_mlp`` drives the trainer from here; callers that need the trained
+    parameters themselves (not just the streamed metrics) build it the same
+    way.
+    """
     from repro.core import topology
     from repro.data.loader import NodeLoader
     from repro.data.synthetic import make_mnist_like
-    from repro.train import metrics as M
     from repro.train.trainer import DecentralizedTrainer
 
     ds = make_mnist_like(**spec.data)
@@ -153,8 +163,6 @@ def _run_mlp(spec: ExperimentSpec, emit: Emit, verbose: bool) -> dict[str, Any]:
     summ = partition_summary(ds.y_train, parts)
     g2_cols = np.flatnonzero(groups == 1)
     holds_g2 = summ[:, g2_cols].sum(axis=1) > 0
-    focus_nodes = np.flatnonzero(holds_g2)
-    spread_nodes = np.flatnonzero(~holds_g2)
 
     loader = NodeLoader(
         ds.x_train, ds.y_train, parts, batch_size=spec.batch_size, seed=spec.seed + 1
@@ -188,6 +196,13 @@ def _run_mlp(spec: ExperimentSpec, emit: Emit, verbose: bool) -> dict[str, Any]:
         class_groups=groups,
         **extra,
     )
+    return trainer, ds, holds_g2
+
+
+def _run_mlp(spec: ExperimentSpec, emit: Emit, verbose: bool) -> dict[str, Any]:
+    trainer, ds, holds_g2 = build_mlp_trainer(spec)
+    focus_nodes = np.flatnonzero(holds_g2)
+    spread_nodes = np.flatnonzero(~holds_g2)
     fault_trace = None
     if trainer.faulted:
         fault_trace = trainer.engine.fault_trace
@@ -479,6 +494,8 @@ def run_sweep(
     run_end are skipped — re-running a finished sweep is a no-op. With
     ``processes > 1``, specs fan out over a spawn-context process pool; each
     worker writes a private shard merged into the main store on completion.
+    The pool runs only under ``JAX_PLATFORMS=cpu``: every worker opens its
+    own JAX backend, and an accelerator belongs to one process at a time.
     """
     store = ResultsStore(store_path)
     shard_dir = store_path + ".shards"
@@ -502,6 +519,14 @@ def run_sweep(
                 run_spec(spec, store, verbose=verbose, raise_on_error=False)
             )
     else:
+        if os.environ.get("JAX_PLATFORMS") != "cpu":
+            raise RuntimeError(
+                f"run_sweep(processes={processes}) would start {processes} JAX "
+                "processes, and an accelerator belongs to one process at a "
+                "time: the workers would fail or hang waiting for the chip. "
+                "Set JAX_PLATFORMS=cpu to fan out on the CPU, or use "
+                "processes=1 to run every spec in this process."
+            )
         import concurrent.futures as cf
         import multiprocessing as mp
 

@@ -158,6 +158,9 @@ def main(argv=None) -> int:
     ap.add_argument("--store", default=None)
     ap.add_argument("--verbose", action="store_true")
     args = ap.parse_args(argv)
+    from repro.core import machine
+
+    machine.use_compile_cache()
     summary = run_serve_eval(
         topology=args.topology, nodes=args.nodes, rounds=args.rounds,
         seed=args.seed, store_path=args.store, verbose=args.verbose,

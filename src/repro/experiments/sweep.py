@@ -17,6 +17,7 @@ import json
 import os
 import sys
 
+from repro.core import machine
 from repro.experiments import analysis, presets, runner
 from repro.experiments.spec import ExperimentSpec
 from repro.experiments.store import ResultsStore
@@ -58,6 +59,7 @@ def main(argv: list[str] | None = None) -> int:
             print(f"{s.run_id}  {s.topology}  {s.partitioner}  seed={s.seed}")
         return 0
 
+    machine.use_compile_cache()
     # Custom spec files get their own store + label, never the preset's.
     matrix_name = (
         os.path.splitext(os.path.basename(args.specs))[0] if args.specs

@@ -13,13 +13,14 @@ Two layouts, two kernels:
    layout (core/sparse.block_ell_from_csr) enumerates, per destination
    block, the distinct *source blocks* its rows touch and stores the
    coupling weights as dense (8, 8) tiles stacked to a lane-aligned
-   (N, 8*KB) array. The grid is (NB, D/bd, KB); at step (b, j, k) the
-   scalar-prefetched index map DMAs the full 8-row slab of source block
-   ``blk_idx[b, k]`` — one aligned (8, bd) transfer instead of eight
-   (1, bd) row gathers — and the VPU/MXU accumulates the (8, 8) @ (8, bd)
-   mini-matmul into an f32 scratch block, flushed at k == KB-1. Every DMA
-   and every tile is sublane-packed: (8, bd) P slabs and 8-row weight
-   strips, nothing narrower than the hardware's native f32 tile height.
+   (N, 8*KB) array, KB a multiple of 16. The grid is (NB, D/bd, KB/16):
+   step (b, j, k) takes one (8, 128) weight block — the 16 tiles of slots
+   16k..16k+15, a block the TPU compiler accepts as it stands — and, through
+   16 scalar-prefetched index maps over the same P, the 16 (8, bd) slabs of
+   source blocks ``blk_idx[b, 16k + i]``: aligned 8-row transfers instead of
+   eight (1, bd) row gathers each. The MXU accumulates the 16 (8, 8) @
+   (8, bd) mini-matmuls, in slot order, into an f32 scratch block, flushed
+   at the last k.
 
 2. **Scalar ELL row-gather** (``sparse_gossip_pallas``) — the original
    per-row kernel, kept as the *interpret-mode fallback*: its grid is
@@ -49,10 +50,12 @@ __all__ = [
     "sparse_gossip_blocked_pallas",
     "DEFAULT_BD",
     "BLOCK_ROWS",
+    "TILES_PER_STEP",
 ]
 
 DEFAULT_BD = 512
 BLOCK_ROWS = 8  # f32 sublane count: the row granularity of the blocked kernel
+TILES_PER_STEP = 16  # (8, 8) weight tiles in one 128-lane weight block
 
 
 # ---------------------------------------------------------------------------
@@ -60,31 +63,34 @@ BLOCK_ROWS = 8  # f32 sublane count: the row granularity of the blocked kernel
 # ---------------------------------------------------------------------------
 
 
-def sparse_gossip_blocked_kernel(idx_ref, val_ref, p_ref, out_ref, acc_ref, *, nkb: int):
-    """One (b, j, k) grid step: acc += W_tile(8, 8) @ P_block(8, bd).
+def sparse_gossip_blocked_kernel(idx_ref, val_ref, *refs, nsteps: int):
+    """One (b, j, k) grid step: acc += sum_i W_tile_i(8, 8) @ P_block_i(8, bd).
 
     Refs:
       idx_ref: (NB, KB) int32 scalar-prefetch (SMEM) — consumed by the index
                maps; unused in the body but part of the kernel signature.
-      val_ref: (8, 8) f32 VMEM — the weight tile coupling destination block b
-               to source block idx_ref[b, k].
-      p_ref:   (8, bd) VMEM — the gathered source block's D-slab.
-      out_ref: (8, bd) output block, written once per (b, j).
-      acc_ref: (8, bd) f32 VMEM scratch accumulator.
+      val_ref: (8, 128) f32 VMEM — the 16 weight tiles coupling destination
+               block b to source blocks idx_ref[b, 16k + i], tile i in lanes
+               8i..8i+7.
+      refs:    16 (8, bd) P slabs (source block idx_ref[b, 16k + i]), then
+               the (8, bd) output block, written once per (b, j), and the
+               (8, bd) f32 VMEM scratch accumulator.
     """
+    p_refs, out_ref, acc_ref = refs[:TILES_PER_STEP], refs[-2], refs[-1]
     k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(
-        val_ref[...],
-        p_ref[...].astype(jnp.float32),
-        preferred_element_type=jnp.float32,
-    )
+    for i, p_ref in enumerate(p_refs):
+        acc_ref[...] += jnp.dot(
+            val_ref[:, i * BLOCK_ROWS:(i + 1) * BLOCK_ROWS],
+            p_ref[...].astype(jnp.float32),
+            preferred_element_type=jnp.float32,
+        )
 
-    @pl.when(k == nkb - 1)
+    @pl.when(k == nsteps - 1)
     def _flush():
         out_ref[...] = acc_ref[...].astype(out_ref.dtype)
 
@@ -100,38 +106,50 @@ def sparse_gossip_blocked_pallas(
 ) -> jax.Array:
     """Blocked-ELL ``W @ P`` with f32 accumulation.
 
-    blk_idx: (NB, KB) int32 source-block ids; blk_val: (NB*8, KB*8) f32
-    stacked weight tiles (core/sparse.block_ell_from_csr). P must be
-    pre-padded to NB*8 rows and a D multiple of ``bd`` (the ops.py wrapper
-    handles padding/unpadding); padded rows/tiles carry weight 0.
+    blk_idx: (NB, KB) int32 source-block ids, KB a multiple of 16; blk_val:
+    (NB*8, KB*8) f32 stacked weight tiles (core/sparse.block_ell_from_csr).
+    P must be pre-padded to NB*8 rows and a D multiple of ``bd`` (the ops.py
+    wrapper handles padding/unpadding); padded rows/tiles carry weight 0.
     """
     nb, kb = blk_idx.shape
     n, d = p.shape
     if n != nb * BLOCK_ROWS:
         raise ValueError(f"P rows {n} != {nb} blocks x {BLOCK_ROWS}")
+    if kb % TILES_PER_STEP:
+        raise ValueError(f"KB={kb} must be a multiple of {TILES_PER_STEP}")
     if blk_val.shape != (nb * BLOCK_ROWS, kb * BLOCK_ROWS):
         raise ValueError(
             f"blk_val {blk_val.shape} != ({nb * BLOCK_ROWS}, {kb * BLOCK_ROWS})"
         )
     if d % bd:
         raise ValueError(f"D={d} must be padded to a multiple of bd={bd}")
-    grid = (nb, d // bd, kb)
+    nsteps = kb // TILES_PER_STEP
+    p_specs = [
+        pl.BlockSpec(
+            (BLOCK_ROWS, bd),
+            lambda b, j, k, idx_ref, i=i: (idx_ref[b, k * TILES_PER_STEP + i], j),
+        )
+        for i in range(TILES_PER_STEP)
+    ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=grid,
+        grid=(nb, d // bd, nsteps),
         in_specs=[
-            pl.BlockSpec((BLOCK_ROWS, BLOCK_ROWS), lambda b, j, k, idx_ref: (b, k)),  # lint: allow[P001] — 8x8 weight tile is the ELL block itself; VPU-only, never fed to the MXU
-            pl.BlockSpec((BLOCK_ROWS, bd), lambda b, j, k, idx_ref: (idx_ref[b, k], j)),
+            pl.BlockSpec(
+                (BLOCK_ROWS, BLOCK_ROWS * TILES_PER_STEP),
+                lambda b, j, k, idx_ref: (b, k),
+            ),
+            *p_specs,
         ],
         out_specs=pl.BlockSpec((BLOCK_ROWS, bd), lambda b, j, k, idx_ref: (b, j)),
         scratch_shapes=[pltpu.VMEM((BLOCK_ROWS, bd), jnp.float32)],
     )
     return pl.pallas_call(
-        functools.partial(sparse_gossip_blocked_kernel, nkb=kb),
+        functools.partial(sparse_gossip_blocked_kernel, nsteps=nsteps),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, d), p.dtype),
         interpret=interpret,
-    )(blk_idx, blk_val.astype(jnp.float32), p)
+    )(blk_idx, blk_val.astype(jnp.float32), *([p] * TILES_PER_STEP))
 
 
 # ---------------------------------------------------------------------------

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import base as cfgbase
+from repro.core import machine
 from repro.models import transformer as TF
 from repro.serve import decode as SD
 
@@ -32,6 +33,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    machine.use_compile_cache()
     cfg = cfgbase.get(args.arch).reduced()
     params = TF.init_params(jax.random.PRNGKey(args.seed), cfg)
     prompt = jax.random.randint(

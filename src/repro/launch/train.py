@@ -21,7 +21,7 @@ from __future__ import annotations
 
 import argparse
 
-from repro.core import decavg
+from repro.core import decavg, machine
 from repro.experiments import runner
 from repro.experiments.spec import ExperimentSpec
 from repro.experiments.store import ResultsStore
@@ -113,6 +113,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    machine.use_compile_cache()
     spec = build_spec(args)
     result = runner.run_spec(spec, ResultsStore(args.store), verbose=True)
     final = result["final"]

@@ -515,8 +515,6 @@ class DecentralizedTrainer:
         replicated draws sliced per slab, and the mix body is the same code
         the loop path runs.
         """
-        from repro.core.decavg import _shard_map
-
         axes = (
             (program.node_axis,) if isinstance(program.node_axis, str)
             else tuple(program.node_axis)
@@ -631,7 +629,7 @@ class DecentralizedTrainer:
         ospec = node_specs(opt_state)
         cspec = node_specs(cstate)
         hspec = node_specs(hist)
-        return _shard_map(
+        return jax.shard_map(
             local_scan, mesh=program.mesh,
             in_specs=(P(), P(), P(), pspec, ospec, cspec, hspec),
             out_specs=(pspec, ospec, cspec, hspec),

@@ -1,87 +1,10 @@
 """Shared fixtures. NOTE: no XLA_FLAGS here on purpose — smoke tests and
 benches must see the single real CPU device (the 512-device override is
 exclusive to launch/dryrun.py). Sharded-path tests spawn subprocesses.
-
-Optional-dependency fallback: several test modules import ``hypothesis``
-(property tests) and ``networkx`` (cross-checks) at module scope, which
-breaks *collection* of the whole module when the package is absent.
-``pytest.importorskip`` can't help there (the import happens before any
-conftest hook runs per-module), so we pre-register stub modules in
-``sys.modules``: property tests and networkx cross-checks then SKIP
-individually instead of erroring the other ~90 tests out of collection.
-Install the real packages (``pip install -e ".[test]"``) to run them.
 """
 
-import sys
-import types
-
-import jax
 import numpy as np
 import pytest
-
-# Shared guard for subprocess tests that build meshes with
-# jax.make_mesh(axis_types=jax.sharding.AxisType...), an API added after jax
-# 0.4.37. Skip (not fail) on older jax so tier-1 stays green in pinned
-# containers without hiding regressions on newer jax — the same
-# sharded/permute numerics run on any jax in tests/test_backend_equivalence.py
-# via plain jax.sharding.Mesh.
-requires_axis_type = pytest.mark.skipif(
-    not hasattr(jax.sharding, "AxisType"),
-    reason="jax.sharding.AxisType requires jax > 0.4.37",
-)
-
-
-def _install_hypothesis_stub() -> None:
-    mod = types.ModuleType("hypothesis")
-
-    def given(*_args, **_kwargs):
-        def deco(fn):
-            # No functools.wraps: pytest would unwrap to the original
-            # signature and demand fixtures for the hypothesis arguments.
-            def skipper(*args, **kwargs):
-                pytest.skip("hypothesis not installed (property test)")
-
-            skipper.__name__ = fn.__name__
-            skipper.__doc__ = fn.__doc__
-            return skipper
-
-        return deco
-
-    class settings:
-        def __init__(self, *args, **kwargs):
-            pass
-
-        def __call__(self, fn):
-            return fn
-
-    strategies = types.ModuleType("hypothesis.strategies")
-    for name in ("integers", "floats", "booleans", "text", "lists",
-                 "sampled_from", "tuples", "one_of", "just"):
-        setattr(strategies, name, lambda *a, _n=name, **k: None)
-
-    mod.given = given
-    mod.settings = settings
-    mod.strategies = strategies
-    sys.modules["hypothesis"] = mod
-    sys.modules["hypothesis.strategies"] = strategies
-
-
-class _SkipOnUse(types.ModuleType):
-    """Module stub whose first attribute access skips the running test."""
-
-    def __getattr__(self, name):
-        pytest.skip(f"{self.__name__} not installed")
-
-
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    _install_hypothesis_stub()
-
-try:
-    import networkx  # noqa: F401
-except ImportError:
-    sys.modules["networkx"] = _SkipOnUse("networkx")
 
 
 @pytest.fixture(scope="session")

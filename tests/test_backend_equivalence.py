@@ -94,7 +94,10 @@ def test_fixed_point_preserved(spec, backend):
         )
 
 
-@pytest.mark.parametrize("spec", TOPOLOGIES)
+# The two extra graphs have N off the 8-row block grid (a ragged last
+# block); er:n=150 has 19 source blocks per destination block, so the
+# kernel accumulates over two 16-tile grid steps, the rest over one.
+@pytest.mark.parametrize("spec", TOPOLOGIES + ["ba:n=30,m=3", "er:n=150,p=0.2"])
 def test_blocked_ell_kernel_matches_mix_sparse(spec):
     """Acceptance: the 8-row-blocked ELL kernel matches the segment-sum
     sparse path to 1e-6 (forced through the interpreter off-TPU)."""

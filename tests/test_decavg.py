@@ -11,8 +11,6 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from conftest import requires_axis_type
-
 from repro.core import decavg as D
 from repro.core import mixing as M
 from repro.core import topology as T
@@ -82,7 +80,6 @@ class TestEquivalence:
         with pytest.raises(ValueError, match="halo_schedule"):
             D.GossipEngine(g, halo_schedule="spiral")
 
-    @requires_axis_type
     def test_dense_vs_shardmap_subprocess(self):
         """shard_map schedules need >1 device: run with 8 fake CPU devices."""
         code = textwrap.dedent(

@@ -340,6 +340,20 @@ class TestBugfixRegressions:
         st = ResultsStore(store_path)
         assert st.completed() == {s.run_id for s in specs}
 
+    def test_multiprocess_sweep_refused_off_cpu(self, tmp_path, monkeypatch):
+        """Worker processes would each open the accelerator: without
+        JAX_PLATFORMS=cpu the pool is refused before anything spawns."""
+        specs = [
+            ExperimentSpec(topology="ring:n=6", **TINY),
+            ExperimentSpec(topology="star:n=6", **TINY),
+        ]
+        store_path = str(tmp_path / "r.jsonl")
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        with pytest.raises(RuntimeError, match="JAX_PLATFORMS=cpu"):
+            runner.run_sweep(specs, store_path, processes=2)
+        assert not os.path.exists(store_path + ".shards")
+        assert ResultsStore(store_path).completed() == set()
+
     def test_graph_records_sampled_above_period_cap(self, monkeypatch):
         """Hundreds of @regen=1 periods must not mean hundreds of post-run
         eigensolves: records are evenly sampled, true count preserved."""

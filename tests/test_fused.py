@@ -181,6 +181,19 @@ class TestFusedEngineBackends:
             assert_trees_close(loop.params, fused.params, rtol=tol, atol=tol)
             assert_trees_close(loop.opt_state, fused.opt_state, rtol=tol, atol=tol)
 
+    def test_sharded_csr_concrete_after_jitted_round(self, setup):
+        """Regression: the loop path's jitted round reads the engine's
+        ShardedCSR; one built lazily inside that trace cached tracers that
+        the next round (or any host read) then tripped over."""
+        tr = make_trainer(
+            setup, topology="er:n=10,p=0.5@rewire=2", mix_impl="sparse_sharded",
+            **self.SMALL,
+        )
+        tr.run(3)  # crosses the period-1 boundary at round 2
+        for leaf in jax.tree.leaves(tr.engine.sharded_csr()):
+            assert not isinstance(leaf, jax.core.Tracer)
+            np.asarray(leaf)  # raises on a leaked tracer
+
     def test_loop_backends_agree_across_periods(self, setup):
         """Regression: ``_jit_for_period`` once jitted the bound method, and
         equal bound methods share one pjit cache entry — after the first

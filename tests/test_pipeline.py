@@ -11,8 +11,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import requires_axis_type
-
 from repro.configs import base as cfgbase
 from repro.models import transformer as TF
 
@@ -34,7 +32,6 @@ def test_int8_kv_cache_close_to_bf16():
     assert max(errs) < 0.05 * max(scale, 1.0), f"int8 err {max(errs)} vs scale {scale}"
 
 
-@requires_axis_type
 def test_sparse_gossip_equals_dense_subprocess():
     code = textwrap.dedent(
         """
@@ -85,7 +82,6 @@ def test_edge_coloring_is_proper():
     inner()
 
 
-@requires_axis_type
 def test_manual_pipeline_matches_decode_subprocess():
     code = textwrap.dedent(
         """
