@@ -247,28 +247,29 @@ def _sharded_mix_leaf(
     """
     idx = jax.lax.axis_index(axes)
     flat = leaf.reshape(leaf.shape[0], -1).astype(jnp.float32)  # (blk, p)
-    if ring:
-        # Halo buffer with one scratch row at slot H: padded local/ring
-        # destinations point there and are discarded by the slice below.
-        buf = jnp.zeros((h + 1, flat.shape[1]), jnp.float32)
-        ls = jax.lax.dynamic_index_in_dim(local_src, idx, 0, keepdims=False)
-        ld = jax.lax.dynamic_index_in_dim(local_dst, idx, 0, keepdims=False)
-        buf = buf.at[ld].set(flat[ls])
-        for d, (sidx, rslot) in enumerate(zip(ring_send, ring_recv), 1):
-            if sidx.shape[1] == 0:
-                continue  # no shard pair exchanges at this distance
-            send = jax.lax.dynamic_index_in_dim(sidx, idx, 0, keepdims=False)
-            got = jax.lax.ppermute(
-                flat[send], axes,
-                [(s, (s + d) % shards) for s in range(shards)],
-            )
-            slot = jax.lax.dynamic_index_in_dim(rslot, idx, 0, keepdims=False)
-            buf = buf.at[slot].set(got)
-        buf = buf[:h]  # (H, p); cols only ever reference [0, H)
-    else:
-        full = jax.lax.all_gather(flat, axes, axis=0, tiled=True)  # (n, p)
-        need = jax.lax.dynamic_index_in_dim(halo, idx, 0, keepdims=False)
-        buf = full[need]  # (H, p): only rows this shard references
+    with jax.named_scope("decavg.halo_exchange"):
+        if ring:
+            # Halo buffer with one scratch row at slot H: padded local/ring
+            # destinations point there and are discarded by the slice below.
+            buf = jnp.zeros((h + 1, flat.shape[1]), jnp.float32)
+            ls = jax.lax.dynamic_index_in_dim(local_src, idx, 0, keepdims=False)
+            ld = jax.lax.dynamic_index_in_dim(local_dst, idx, 0, keepdims=False)
+            buf = buf.at[ld].set(flat[ls])
+            for d, (sidx, rslot) in enumerate(zip(ring_send, ring_recv), 1):
+                if sidx.shape[1] == 0:
+                    continue  # no shard pair exchanges at this distance
+                send = jax.lax.dynamic_index_in_dim(sidx, idx, 0, keepdims=False)
+                got = jax.lax.ppermute(
+                    flat[send], axes,
+                    [(s, (s + d) % shards) for s in range(shards)],
+                )
+                slot = jax.lax.dynamic_index_in_dim(rslot, idx, 0, keepdims=False)
+                buf = buf.at[slot].set(got)
+            buf = buf[:h]  # (H, p); cols only ever reference [0, H)
+        else:
+            full = jax.lax.all_gather(flat, axes, axis=0, tiled=True)  # (n, p)
+            need = jax.lax.dynamic_index_in_dim(halo, idx, 0, keepdims=False)
+            buf = full[need]  # (H, p): only rows this shard references
     r = jax.lax.dynamic_index_in_dim(rows, idx, 0, keepdims=False)
     c = jax.lax.dynamic_index_in_dim(cols, idx, 0, keepdims=False)
     v = jax.lax.dynamic_index_in_dim(values, idx, 0, keepdims=False)
@@ -395,25 +396,26 @@ def _sharded_mix_leaf_faulted(
     curf = cur.reshape(cur.shape[0], -1).astype(jnp.float32)  # (blk, p)
     pubf = pub.reshape(pub.shape[0], -1).astype(jnp.float32)
     halo_s = jax.lax.dynamic_index_in_dim(halo, idx, 0, keepdims=False)
-    if ring:
-        buf = jnp.zeros((h + 1, pubf.shape[1]), jnp.float32)
-        ls = jax.lax.dynamic_index_in_dim(local_src, idx, 0, keepdims=False)
-        ld = jax.lax.dynamic_index_in_dim(local_dst, idx, 0, keepdims=False)
-        buf = buf.at[ld].set(pubf[ls])
-        for d, (sidx, rslot) in enumerate(zip(ring_send, ring_recv), 1):
-            if sidx.shape[1] == 0:
-                continue
-            send = jax.lax.dynamic_index_in_dim(sidx, idx, 0, keepdims=False)
-            got = jax.lax.ppermute(
-                pubf[send], axes,
-                [(s, (s + d) % shards) for s in range(shards)],
-            )
-            slot = jax.lax.dynamic_index_in_dim(rslot, idx, 0, keepdims=False)
-            buf = buf.at[slot].set(got)
-        buf = buf[:h]
-    else:
-        full = jax.lax.all_gather(pubf, axes, axis=0, tiled=True)  # (n, p)
-        buf = full[halo_s]
+    with jax.named_scope("decavg.halo_exchange"):
+        if ring:
+            buf = jnp.zeros((h + 1, pubf.shape[1]), jnp.float32)
+            ls = jax.lax.dynamic_index_in_dim(local_src, idx, 0, keepdims=False)
+            ld = jax.lax.dynamic_index_in_dim(local_dst, idx, 0, keepdims=False)
+            buf = buf.at[ld].set(pubf[ls])
+            for d, (sidx, rslot) in enumerate(zip(ring_send, ring_recv), 1):
+                if sidx.shape[1] == 0:
+                    continue
+                send = jax.lax.dynamic_index_in_dim(sidx, idx, 0, keepdims=False)
+                got = jax.lax.ppermute(
+                    pubf[send], axes,
+                    [(s, (s + d) % shards) for s in range(shards)],
+                )
+                slot = jax.lax.dynamic_index_in_dim(rslot, idx, 0, keepdims=False)
+                buf = buf.at[slot].set(got)
+            buf = buf[:h]
+        else:
+            full = jax.lax.all_gather(pubf, axes, axis=0, tiled=True)  # (n, p)
+            buf = full[halo_s]
     r = jax.lax.dynamic_index_in_dim(rows, idx, 0, keepdims=False)
     c = jax.lax.dynamic_index_in_dim(cols, idx, 0, keepdims=False)
     v = jax.lax.dynamic_index_in_dim(values, idx, 0, keepdims=False)
@@ -762,16 +764,17 @@ class MixingProgram:
         """``apply`` gated by the gossip cadence (identity on skip rounds)."""
         if self.cadence == "never":
             return params
-        if self.cadence == "always":
-            return self.apply(params, r, pub)
-        if pub is None:
+        with jax.named_scope("decavg.mix"):
+            if self.cadence == "always":
+                return self.apply(params, r, pub)
+            if pub is None:
+                return jax.lax.cond(
+                    self.gossip_mask[r], lambda p: self.apply(p, r), lambda p: p, params
+                )
             return jax.lax.cond(
-                self.gossip_mask[r], lambda p: self.apply(p, r), lambda p: p, params
+                self.gossip_mask[r],
+                lambda a: self.apply(a[0], r, a[1]), lambda a: a[0], (params, pub),
             )
-        return jax.lax.cond(
-            self.gossip_mask[r],
-            lambda a: self.apply(a[0], r, a[1]), lambda a: a[0], (params, pub),
-        )
 
     def _sharded_static(self) -> tuple[tuple[str, ...], bool, int]:
         """(axes, ring?, blk) for the stacked sharded layout. The ring/
@@ -837,18 +840,19 @@ class MixingProgram:
         """``apply_local`` gated by the gossip cadence (cf. ``mix_at``)."""
         if self.cadence == "never":
             return params
-        if self.cadence == "always":
-            return self.apply_local(params, r, pub)
-        if pub is None:
+        with jax.named_scope("decavg.mix"):
+            if self.cadence == "always":
+                return self.apply_local(params, r, pub)
+            if pub is None:
+                return jax.lax.cond(
+                    self.gossip_mask[r],
+                    lambda p: self.apply_local(p, r), lambda p: p, params,
+                )
             return jax.lax.cond(
                 self.gossip_mask[r],
-                lambda p: self.apply_local(p, r), lambda p: p, params,
+                lambda a: self.apply_local(a[0], r, a[1]), lambda a: a[0],
+                (params, pub),
             )
-        return jax.lax.cond(
-            self.gossip_mask[r],
-            lambda a: self.apply_local(a[0], r, a[1]), lambda a: a[0],
-            (params, pub),
-        )
 
 
 # ---------------------------------------------------------------------------

@@ -57,6 +57,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.core import compress as compress_mod
 from repro.core import decavg
 from repro.core.topology import Graph, TopologySchedule
@@ -237,23 +238,50 @@ class DecentralizedTrainer:
         """The current-period mixing operand passed into the round closure."""
         return self.engine.csr if self.mix_impl == "sparse" else self.w
 
+    def _sgd_step(self, params, opt_state, x, y):
+        """One SGD-with-momentum step of every node on its own batch:
+        ``x`` (N, B, D), ``y`` (N, B)."""
+
+        def node_loss(p, xb, yb):
+            return softmax_xent(self.forward(p, xb), yb)
+
+        with jax.named_scope("decavg.local_grad"):
+            grads = jax.vmap(jax.grad(node_loss))(params, x, y)
+        # sgd.update broadcasts fine over the stacked node axis.
+        with jax.named_scope("decavg.sgd_update"):
+            return sgd.update(grads, opt_state, params, lr=self.lr, mu=self.mu)
+
     def _local_steps(self, params, opt_state, xs, ys):
         """xs: (steps, N, B, D); one vmapped SGD step per element of steps."""
 
         def one_step(carry, batch):
-            params, opt = carry
-            x, y = batch  # (N, B, D), (N, B)
-
-            def node_loss(p, xb, yb):
-                return softmax_xent(self.forward(p, xb), yb)
-
-            grads = jax.vmap(jax.grad(node_loss))(params, x, y)
-            # sgd.update broadcasts fine over the stacked node axis.
-            params, opt = sgd.update(grads, opt, params, lr=self.lr, mu=self.mu)
-            return (params, opt), None
+            return self._sgd_step(*carry, *batch), None
 
         (params, opt_state), _ = jax.lax.scan(one_step, (params, opt_state), (xs, ys))
         return params, opt_state
+
+    @staticmethod
+    def _gather_batch(data, node, idx):
+        """Each node's batch from the staged dataset: node ``node[i]`` takes
+        its bank rows ``idx[i]`` (B,)."""
+        with jax.named_scope("decavg.batch"):
+            rows = data.parts[node[:, None], idx]  # (N, B) bank rows
+            return data.x[rows], data.y[rows]
+
+    def _fault_mask(self, alive, r, delay, params, opt_state, p_in, o_in, hist):
+        """Freeze dead nodes back to their pre-round params AND momentum
+        (exactly equivalent to never training them) and push the round into
+        the straggler ring buffer. Returns (params, opt_state, published
+        snapshots or None, hist)."""
+        from repro.core import faults as faults_mod
+
+        with jax.named_scope("decavg.fault_mask"):
+            params = faults_mod.where_alive(alive, params, p_in)
+            opt_state = faults_mod.where_alive(alive, opt_state, o_in)
+            pub = None
+            if self._has_hist:
+                pub, hist = faults_mod.push_and_publish(params, hist, r, delay)
+        return params, opt_state, pub, hist
 
     def _gossip(self, mix, params, cstate):
         """One gossip exchange via ``mix`` (a params->params mixing closure).
@@ -265,12 +293,14 @@ class DecentralizedTrainer:
         to ``params = W @ params``.
         """
         if self.compress is None:
-            return mix(params), cstate
+            with jax.named_scope("decavg.mix"):
+                return mix(params), cstate
         _, cstate = jax.vmap(
             functools.partial(compress_mod.compress, k_frac=self.compress)
         )(params, cstate)
         ref = cstate.reference
-        mixed = mix(ref)
+        with jax.named_scope("decavg.mix"):
+            mixed = mix(ref)
         params = jax.tree.map(
             lambda p, m, r: (p.astype(jnp.float32) + (m - r)).astype(p.dtype),
             params, mixed, ref,
@@ -318,51 +348,41 @@ class DecentralizedTrainer:
     def _mix_faulted(self, op, keep, alive, cur, pub):
         from repro.core import faults as faults_mod
 
-        if self.mix_impl == "dense":
-            return faults_mod.mix_faulted_dense(op, keep, alive, cur, pub)
-        if self.mix_impl == "sparse":
-            return faults_mod.mix_faulted_csr(
-                op.rows, op.indices, op.values, keep, alive,
-                self.num_nodes, cur, pub,
+        with jax.named_scope("decavg.mix"):
+            if self.mix_impl == "dense":
+                return faults_mod.mix_faulted_dense(op, keep, alive, cur, pub)
+            if self.mix_impl == "sparse":
+                return faults_mod.mix_faulted_csr(
+                    op.rows, op.indices, op.values, keep, alive,
+                    self.num_nodes, cur, pub,
+                )
+            return decavg.mix_sharded_sparse_faulted(
+                op, cur, cur if pub is None else pub, keep, alive,
+                mesh=self.engine.mesh, node_axis=self.engine.node_axis,
+                halo_schedule=self.engine.halo_schedule,
             )
-        return decavg.mix_sharded_sparse_faulted(
-            op, cur, cur if pub is None else pub, keep, alive,
-            mesh=self.engine.mesh, node_axis=self.engine.node_axis,
-            halo_schedule=self.engine.halo_schedule,
-        )
 
     def _round_faulted(self, op, keep, alive, r, params, opt_state, hist, xs, ys):
         """One faulted gossip round: train, freeze dead nodes back to their
         pre-round state (params AND momentum — exactly equivalent to never
         training them), advance the straggler ring buffer, mix the published
         snapshots over the surviving renormalized W."""
-        from repro.core import faults as faults_mod
-
         p_in, o_in = params, opt_state
         params, opt_state = self._local_steps(params, opt_state, xs, ys)
-        params = faults_mod.where_alive(alive, params, p_in)
-        opt_state = faults_mod.where_alive(alive, opt_state, o_in)
-        pub = None
-        if self._has_hist:
-            pub, hist = faults_mod.push_and_publish(
-                params, hist, r, self._fault_delay
-            )
+        params, opt_state, pub, hist = self._fault_mask(
+            alive, r, self._fault_delay, params, opt_state, p_in, o_in, hist
+        )
         params = self._mix_faulted(op, keep, alive, params, pub)
         return params, opt_state, hist
 
     def _local_faulted(self, r, alive, params, opt_state, hist, xs, ys):
         """A faulted non-gossip round: train + freeze + history push (a
         straggler's clock advances whether or not the round gossips)."""
-        from repro.core import faults as faults_mod
-
         p_in, o_in = params, opt_state
         params, opt_state = self._local_steps(params, opt_state, xs, ys)
-        params = faults_mod.where_alive(alive, params, p_in)
-        opt_state = faults_mod.where_alive(alive, opt_state, o_in)
-        if self._has_hist:
-            _, hist = faults_mod.push_and_publish(
-                params, hist, r, self._fault_delay
-            )
+        params, opt_state, _, hist = self._fault_mask(
+            alive, r, self._fault_delay, params, opt_state, p_in, o_in, hist
+        )
         return params, opt_state, hist
 
     def _eval(self, params, x_test, y_test):
@@ -372,7 +392,8 @@ class DecentralizedTrainer:
                 logits, y_test, self.num_classes
             )
 
-        return jax.vmap(node_metrics)(params)
+        with jax.named_scope("decavg.eval"):
+            return jax.vmap(node_metrics)(params)
 
     def _group_eval(self, params, x_test, y_test):
         """Per-node (accuracy, per-group accuracy); used when class_groups set."""
@@ -383,7 +404,19 @@ class DecentralizedTrainer:
                 logits, y_test, self.class_groups, self.num_groups
             )
 
-        return jax.vmap(node_metrics)(params)
+        with jax.named_scope("decavg.eval"):
+            return jax.vmap(node_metrics)(params)
+
+    def _chunk_eval(self, params, x_test, y_test):
+        """A fused chunk's metrics: (accs, group accs or None, consensus)."""
+        if self.class_groups is not None:
+            accs, gaccs = self._group_eval(params, x_test, y_test)
+        else:
+            accs, _ = self._eval(params, x_test, y_test)
+            gaccs = None
+        with jax.named_scope("decavg.eval"):
+            cons = consensus_distance(params)
+        return accs, gaccs, cons
 
     def _fused_chunk(
         self, program, data, params, opt_state, cstate, hist, start,
@@ -407,13 +440,8 @@ class DecentralizedTrainer:
             )
             if not do_eval:
                 return params, opt_state, cstate, hist, None
-            if self.class_groups is not None:
-                accs, gaccs = self._group_eval(params, x_test, y_test)
-            else:
-                accs, _ = self._eval(params, x_test, y_test)
-                gaccs = None
-            cons = consensus_distance(params)
-            return params, opt_state, cstate, hist, (accs, gaccs, cons)
+            metrics = self._chunk_eval(params, x_test, y_test)
+            return params, opt_state, cstate, hist, metrics
         node = jnp.arange(self.num_nodes)
         hoist = (
             length * steps * self.num_nodes * self.loader.batch
@@ -426,36 +454,22 @@ class DecentralizedTrainer:
                 r, idx = x
             else:
                 r = x
-                idx = round_batch_indices(
-                    data.key, r, steps, self.loader.batch, data.sizes
-                )
+                with jax.named_scope("decavg.batch"):
+                    idx = round_batch_indices(
+                        data.key, r, steps, self.loader.batch, data.sizes
+                    )
 
             def one_step(c, idx_s):
-                p, o = c
-                rows = data.parts[node[:, None], idx_s]  # (N, B) bank rows
-                x = data.x[rows]
-                y = data.y[rows]
-
-                def node_loss(pp, xb, yb):
-                    return softmax_xent(self.forward(pp, xb), yb)
-
-                grads = jax.vmap(jax.grad(node_loss))(p, x, y)
-                p, o = sgd.update(grads, o, p, lr=self.lr, mu=self.mu)
-                return (p, o), None
+                x, y = self._gather_batch(data, node, idx_s)
+                return self._sgd_step(*c, x, y), None
 
             p_in, o_in = params, opt
             (params, opt), _ = jax.lax.scan(one_step, (params, opt), idx)
             if self.faulted:
-                from repro.core import faults as faults_mod
-
-                alive = program.f_alive[r]
-                params = faults_mod.where_alive(alive, params, p_in)
-                opt = faults_mod.where_alive(alive, opt, o_in)
-                pub = None
-                if self._has_hist:
-                    pub, hist = faults_mod.push_and_publish(
-                        params, hist, r, program.f_delay
-                    )
+                params, opt, pub, hist = self._fault_mask(
+                    program.f_alive[r], r, program.f_delay,
+                    params, opt, p_in, o_in, hist,
+                )
                 params = program.mix_at(params, r, pub)
             elif self.compress is None:
                 params = program.mix_at(params, r)
@@ -476,11 +490,12 @@ class DecentralizedTrainer:
 
         rs = start + jnp.arange(length)
         if hoist:
-            idx_all = jax.vmap(
-                lambda r: round_batch_indices(
-                    data.key, r, steps, self.loader.batch, data.sizes
-                )
-            )(rs)
+            with jax.named_scope("decavg.batch"):
+                idx_all = jax.vmap(
+                    lambda r: round_batch_indices(
+                        data.key, r, steps, self.loader.batch, data.sizes
+                    )
+                )(rs)
             xs = (rs, idx_all)
         else:
             xs = rs
@@ -489,13 +504,8 @@ class DecentralizedTrainer:
         )
         if not do_eval:
             return params, opt_state, cstate, hist, None
-        if self.class_groups is not None:
-            accs, gaccs = self._group_eval(params, x_test, y_test)
-        else:
-            accs, _ = self._eval(params, x_test, y_test)
-            gaccs = None
-        cons = consensus_distance(params)
-        return params, opt_state, cstate, hist, (accs, gaccs, cons)
+        metrics = self._chunk_eval(params, x_test, y_test)
+        return params, opt_state, cstate, hist, metrics
 
     def _scan_rounds_sharded(
         self, program, data, params, opt_state, cstate, hist, start,
@@ -528,8 +538,6 @@ class DecentralizedTrainer:
             sidx = jax.lax.axis_index(axes)
             gnode = sidx * blk + jnp.arange(blk)  # slab's global node ids
             if self.faulted:
-                from repro.core import faults as faults_mod
-
                 # Static per-node staleness, pre-sliced to this slab once.
                 delay_s = jax.lax.dynamic_slice_in_dim(
                     program.f_delay, sidx * blk, blk
@@ -545,25 +553,17 @@ class DecentralizedTrainer:
                     # (identical to the host/loop draws) and slices its own
                     # slab's rows.
                     r = x
-                    idx = round_batch_indices(
-                        data.key, r, steps, batch, data.sizes
-                    )
-                    idx = jax.lax.dynamic_slice_in_dim(
-                        idx, sidx * blk, blk, axis=1
-                    )
+                    with jax.named_scope("decavg.batch"):
+                        idx = round_batch_indices(
+                            data.key, r, steps, batch, data.sizes
+                        )
+                        idx = jax.lax.dynamic_slice_in_dim(
+                            idx, sidx * blk, blk, axis=1
+                        )
 
                 def one_step(c, idx_s):
-                    p, o = c
-                    rows = data.parts[gnode[:, None], idx_s]  # (blk, B)
-                    x = data.x[rows]
-                    y = data.y[rows]
-
-                    def node_loss(pp, xb, yb):
-                        return softmax_xent(self.forward(pp, xb), yb)
-
-                    grads = jax.vmap(jax.grad(node_loss))(p, x, y)
-                    p, o = sgd.update(grads, o, p, lr=self.lr, mu=self.mu)
-                    return (p, o), None
+                    x, y = self._gather_batch(data, gnode, idx_s)
+                    return self._sgd_step(*c, x, y), None
 
                 p_in, o_in = params, opt
                 (params, opt), _ = jax.lax.scan(one_step, (params, opt), idx)
@@ -573,13 +573,9 @@ class DecentralizedTrainer:
                     alive_s = jax.lax.dynamic_slice_in_dim(
                         program.f_alive[r], sidx * blk, blk
                     )
-                    params = faults_mod.where_alive(alive_s, params, p_in)
-                    opt = faults_mod.where_alive(alive_s, opt, o_in)
-                    pub = None
-                    if self._has_hist:
-                        pub, hist = faults_mod.push_and_publish(
-                            params, hist, r, delay_s
-                        )
+                    params, opt, pub, hist = self._fault_mask(
+                        alive_s, r, delay_s, params, opt, p_in, o_in, hist
+                    )
                     params = program.mix_at_local(params, r, pub)
                 elif self.compress is None:
                     params = program.mix_at_local(params, r)
@@ -604,14 +600,15 @@ class DecentralizedTrainer:
                 # One vmapped draw for the whole chunk (bit-identical to
                 # the per-round draws), pre-sliced to this device's slab so
                 # the staged xs tensor is 1/S the replicated size.
-                idx_all = jax.vmap(
-                    lambda r: round_batch_indices(
-                        data.key, r, steps, batch, data.sizes
+                with jax.named_scope("decavg.batch"):
+                    idx_all = jax.vmap(
+                        lambda r: round_batch_indices(
+                            data.key, r, steps, batch, data.sizes
+                        )
+                    )(rs)
+                    idx_all = jax.lax.dynamic_slice_in_dim(
+                        idx_all, sidx * blk, blk, axis=2
                     )
-                )(rs)
-                idx_all = jax.lax.dynamic_slice_in_dim(
-                    idx_all, sidx * blk, blk, axis=2
-                )
                 xs = (rs, idx_all)
             else:
                 xs = rs
@@ -664,6 +661,54 @@ class DecentralizedTrainer:
                 self._round_jit_cache.pop(next(iter(self._round_jit_cache)))
             self._round_jit_cache[period] = jitted
         return jitted
+
+    def _stage_fused(self, rounds, x_test, y_test):
+        """What a ``run_fused`` call puts on the device before its first
+        chunk: (mixing program, dataset, straggler history, test set)."""
+        with jax.profiler.TraceAnnotation("trainer.stage"):
+            program = self.engine.program(rounds, kind=self.mix_impl)
+            data = self.loader.device_data()
+            hist = ()
+            if self.faulted and self._has_hist:
+                from repro.core import faults as faults_mod
+
+                hist = faults_mod.init_history(self.params, program.delay_max + 1)
+            if program.kind == "sparse_sharded":
+                # Commit the node-stacked state to its in-scan layout (node
+                # axis sharded over the mesh) before the first chunk: the
+                # fused chunk both consumes and produces this layout, so
+                # without the upfront put the first call compiles for
+                # replicated inputs and the second call recompiles for
+                # sharded ones.
+                from jax.sharding import NamedSharding
+
+                axes = (
+                    (program.node_axis,) if isinstance(program.node_axis, str)
+                    else tuple(program.node_axis)
+                )
+
+                def put(tree):
+                    return jax.tree.map(
+                        lambda l: jax.device_put(
+                            l, NamedSharding(program.mesh, P(axes, *([None] * (l.ndim - 1))))
+                        ),
+                        tree,
+                    )
+
+                self.params = put(self.params)
+                self.opt_state = put(self.opt_state)
+                self.cstate = put(self.cstate)
+                hist = put(hist)
+            x_t, y_t = (
+                (None, None) if x_test is None else (jnp.asarray(x_test), jnp.asarray(y_test))
+            )
+        return program, data, hist, x_t, y_t
+
+    def _fused_chunks(self, rounds: int, eval_every: int, do_eval: bool) -> list[tuple[int, int, int]]:
+        """(last round, first round, length) of each fused chunk: chunks end
+        at the eval rounds, and without an eval the run is one chunk."""
+        ends = self._eval_rounds(rounds, eval_every) if do_eval else [rounds - 1]
+        return [(end, prev + 1, end - prev) for prev, end in zip([-1, *ends], ends)]
 
     # -- public API ---------------------------------------------------------
 
@@ -811,77 +856,41 @@ class DecentralizedTrainer:
             )
         if rounds < 1:
             return []
-        program = self.engine.program(rounds, kind=self.mix_impl)
-        data = self.loader.device_data()
-        hist = ()
-        if self.faulted and self._has_hist:
-            from repro.core import faults as faults_mod
-
-            hist = faults_mod.init_history(self.params, program.delay_max + 1)
-        if program.kind == "sparse_sharded":
-            # Commit the node-stacked state to its in-scan layout (node axis
-            # sharded over the mesh) before the first chunk: the fused chunk
-            # both consumes and produces this layout, so without the upfront
-            # put the first call compiles for replicated inputs and the
-            # second call recompiles for sharded ones.
-            from jax.sharding import NamedSharding
-            from jax.sharding import PartitionSpec as _P
-
-            axes = (
-                (program.node_axis,) if isinstance(program.node_axis, str)
-                else tuple(program.node_axis)
+        if gossip_first and self.faulted:
+            raise ValueError(
+                "gossip_first does not compose with faults= (there is no "
+                "round index for the pre-round mix to draw masks from)"
             )
-
-            def _put(tree):
-                return jax.tree.map(
-                    lambda l: jax.device_put(
-                        l,
-                        NamedSharding(
-                            program.mesh, _P(axes, *([None] * (l.ndim - 1)))
-                        ),
-                    ),
-                    tree,
-                )
-
-            self.params = _put(self.params)
-            self.opt_state = _put(self.opt_state)
-            self.cstate = _put(self.cstate)
-            hist = _put(hist)
+        do_eval = x_test is not None
+        program, data, hist, x_t, y_t = self._stage_fused(rounds, x_test, y_test)
         t0 = time.perf_counter()
         if gossip_first:
-            if self.faulted:
-                raise ValueError(
-                    "gossip_first does not compose with faults= (there is no "
-                    "round index for the pre-round mix to draw masks from)"
-                )
             self.params = self._mix(self._mix_op(), self.params)
-        do_eval = x_test is not None
-        if do_eval:
-            x_t, y_t = jnp.asarray(x_test), jnp.asarray(y_test)
-            ends = self._eval_rounds(rounds, eval_every)
-        else:
-            x_t = y_t = None
-            ends = [rounds - 1]
+
+        def fetch(a):
+            obs.count("trainer.d2h_transfers")
+            return np.asarray(a)
+
         history: list[RoundMetrics] = []
-        prev = -1
-        for end in ends:
-            start, length = prev + 1, end - prev
-            prev = end
-            (
-                self.params, self.opt_state, self.cstate, hist, metrics,
-            ) = self._fused_chunk_jit(
-                program, data, self.params, self.opt_state, self.cstate, hist,
-                jnp.int32(start), x_t, y_t, length=length, do_eval=do_eval,
-            )
+        for end, start, length in self._fused_chunks(rounds, eval_every, do_eval):
+            with jax.profiler.TraceAnnotation("trainer.dispatch"):
+                (
+                    self.params, self.opt_state, self.cstate, hist, metrics,
+                ) = self._fused_chunk_jit(
+                    program, data, self.params, self.opt_state, self.cstate, hist,
+                    jnp.int32(start), x_t, y_t, length=length, do_eval=do_eval,
+                )
+            obs.count("trainer.rounds", length)
             if not do_eval:
                 continue
-            accs, gaccs, cons = metrics
-            accs = np.asarray(accs)
-            m = RoundMetrics(
-                end, accs, float(accs.mean()), float(accs.std()),
-                group_acc=None if gaccs is None else np.asarray(gaccs),
-                consensus=np.asarray(cons), wall_s=time.perf_counter() - t0,
-            )
+            with jax.profiler.TraceAnnotation("trainer.fetch"):
+                accs, gaccs, cons = metrics
+                accs = fetch(accs)
+                m = RoundMetrics(
+                    end, accs, float(accs.mean()), float(accs.std()),
+                    group_acc=None if gaccs is None else fetch(gaccs),
+                    consensus=fetch(cons), wall_s=time.perf_counter() - t0,
+                )
             history.append(m)
             if on_round is not None:
                 on_round(m)
@@ -891,6 +900,39 @@ class DecentralizedTrainer:
                     f"std {accs.std():.4f} min {accs.min():.4f} max {accs.max():.4f}"
                 )
         return history
+
+    def fused_chunk_hlo(
+        self,
+        rounds: int,
+        *,
+        eval_every: int = 1,
+        x_test: np.ndarray | None = None,
+        y_test: np.ndarray | None = None,
+    ) -> dict[int, str]:
+        """The compiled text of each chunk program ``run_fused`` runs with
+        these arguments, by chunk length. Its instructions' ``op_name``
+        metadata carries the ``decavg.*`` scopes (``repro/obs.py``), which a
+        device trace does not: a trace reduction maps each traced op to its
+        scope through this text.
+
+        Compiles afresh: the persistent compilation cache keys a program
+        without its metadata, so a cached executable of the same program
+        built with other scopes, or none, would answer with its own."""
+        do_eval = x_test is not None
+        program, data, hist, x_t, y_t = self._stage_fused(rounds, x_test, y_test)
+        out: dict[int, str] = {}
+        cache_on = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        try:
+            for _, start, length in self._fused_chunks(rounds, eval_every, do_eval):
+                if length not in out:
+                    out[length] = self._fused_chunk_jit.lower(
+                        program, data, self.params, self.opt_state, self.cstate, hist,
+                        jnp.int32(start), x_t, y_t, length=length, do_eval=do_eval,
+                    ).compile().as_text()
+        finally:
+            jax.config.update("jax_enable_compilation_cache", cache_on)
+        return out
 
     def confusion(self, x_test: np.ndarray, y_test: np.ndarray) -> np.ndarray:
         _, cms = self._eval_jit(self.params, jnp.asarray(x_test), jnp.asarray(y_test))
