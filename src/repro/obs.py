@@ -6,7 +6,9 @@ find them:
 - Device scopes (``jax.named_scope``; they only add HLO metadata, so each
   compiled op's ``op_name`` carries the innermost one):
   ``decavg.batch`` (index draw and batch gather), ``decavg.local_grad``
-  (vmapped forward and backward), ``decavg.sgd_update``,
+  (vmapped forward and backward; on a TPU, where the local-round kernel
+  runs, the whole round's local steps with their updates and image
+  fetches), ``decavg.sgd_update``,
   ``decavg.fault_mask`` (dead-node freeze, straggler snapshots),
   ``decavg.mix`` (every ``MixingProgram`` backend), ``decavg.halo_exchange``
   (the sharded mix's ppermute ring or all-gather, inside ``decavg.mix``) and
@@ -18,8 +20,11 @@ find them:
   chunk enqueued) and ``trainer.fetch`` (a chunk's metrics brought to the
   host).
 - Counters, process-wide, read with ``counters()``: ``trainer.rounds``
-  (rounds ``run_fused`` ran) and ``trainer.d2h_transfers`` (blocking
-  device-to-host conversions in its fetch).
+  (rounds ``run_fused`` ran), ``trainer.local_kernel_rounds`` (those of
+  them whose local steps ran in the Pallas kernel,
+  ``kernels/local_round.py``, rather than XLA's scan) and
+  ``trainer.d2h_transfers`` (blocking device-to-host conversions in its
+  fetch).
 """
 
 from __future__ import annotations

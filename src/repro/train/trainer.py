@@ -5,7 +5,9 @@ One *communication round* (paper §3):
   2. every node replaces its weights by the Eq. 1 neighborhood average.
 
 All nodes advance in lockstep as node-stacked pytrees — local training is a
-``vmap`` over the node axis, the gossip is a GossipEngine round
+``vmap`` over the node axis (on a TPU, ``run_fused`` runs the paper MLP's
+local steps in one Pallas kernel a round, ``kernels/local_round.py``), the
+gossip is a GossipEngine round
 (core/decavg.py: XLA einsum, Pallas kernel, or sparse CSR). Momentum is
 node-local and is *not* averaged (the paper gossips model weights only).
 
@@ -62,6 +64,7 @@ from repro.core import compress as compress_mod
 from repro.core import decavg
 from repro.core.topology import Graph, TopologySchedule
 from repro.data.loader import NodeLoader, round_batch_indices
+from repro.kernels import local_round, ops
 from repro.models.mlp import init_mlp, mlp_forward
 from repro.optim import sgd
 from repro.train.losses import softmax_xent
@@ -260,6 +263,40 @@ class DecentralizedTrainer:
         (params, opt_state), _ = jax.lax.scan(one_step, (params, opt_state), (xs, ys))
         return params, opt_state
 
+    def _local_kernel_ok(self, kind: str, params, opt_state, x) -> bool:
+        """Whether a fused round's local steps run in the Pallas kernel
+        (``kernels/local_round.py``) rather than XLA's scan over
+        ``_sgd_step``. Decided only by what the trainer observes: a TPU, a
+        single-device program without faults or compression, the paper
+        MLP's forward, and state the kernel ``fits``."""
+        return (
+            ops.on_tpu()
+            and kind != "sparse_sharded"
+            and not self.faulted
+            and self.compress is None
+            and self.forward is mlp_forward
+            and local_round.fits(
+                params, opt_state.momentum, x,
+                steps=self.loader.steps_per_epoch() * self.local_epochs,
+                batch=self.loader.batch,
+            )
+        )
+
+    def _kernel_local_steps(self, data, node, idx, params, opt_state):
+        """A round's local steps (``idx`` (steps, N, B) pool positions) in
+        one Pallas kernel that keeps each node's state in VMEM across them.
+        Only the rows and labels are gathered here; the kernel fetches the
+        images itself."""
+        with jax.named_scope("decavg.batch"):
+            rows = data.parts[node[:, None, None], jnp.swapaxes(idx, 0, 1)]
+            labels = data.y[rows]
+        with jax.named_scope("decavg.local_grad"):
+            params, mom = local_round.local_round(
+                data.x, rows, labels, params, opt_state.momentum,
+                lr=self.lr, mu=self.mu,
+            )
+        return params, sgd.SGDState(mom)
+
     @staticmethod
     def _gather_batch(data, node, idx):
         """Each node's batch from the staged dataset: node ``node[i]`` takes
@@ -447,6 +484,7 @@ class DecentralizedTrainer:
             length * steps * self.num_nodes * self.loader.batch
             <= _IDX_HOIST_MAX_ELEMS
         )
+        kernel = self._local_kernel_ok(program.kind, params, opt_state, data.x)
 
         def one_round(carry, x):
             params, opt, cstate, hist = carry
@@ -464,7 +502,10 @@ class DecentralizedTrainer:
                 return self._sgd_step(*c, x, y), None
 
             p_in, o_in = params, opt
-            (params, opt), _ = jax.lax.scan(one_step, (params, opt), idx)
+            if kernel:
+                params, opt = self._kernel_local_steps(data, node, idx, params, opt)
+            else:
+                (params, opt), _ = jax.lax.scan(one_step, (params, opt), idx)
             if self.faulted:
                 params, opt, pub, hist = self._fault_mask(
                     program.f_alive[r], r, program.f_delay,
@@ -871,6 +912,7 @@ class DecentralizedTrainer:
             obs.count("trainer.d2h_transfers")
             return np.asarray(a)
 
+        kernel = self._local_kernel_ok(program.kind, self.params, self.opt_state, data.x)
         history: list[RoundMetrics] = []
         for end, start, length in self._fused_chunks(rounds, eval_every, do_eval):
             with jax.profiler.TraceAnnotation("trainer.dispatch"):
@@ -881,6 +923,8 @@ class DecentralizedTrainer:
                     jnp.int32(start), x_t, y_t, length=length, do_eval=do_eval,
                 )
             obs.count("trainer.rounds", length)
+            if kernel:
+                obs.count("trainer.local_kernel_rounds", length)
             if not do_eval:
                 continue
             with jax.profiler.TraceAnnotation("trainer.fetch"):

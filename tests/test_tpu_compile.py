@@ -9,7 +9,9 @@ tiling, VMEM overuse, unsupported ops inside a kernel.
 
 Widths are those ``chip_smoke.py`` runs on the chip: llama3.2-1b attention
 heads at S = T = 1024, the paper MLP gossiped over N = 100 nodes, and the
-``large_n`` preset's BA n=4096 graph with its hidden=[64] member.
+``large_n`` preset's BA n=4096 graph with its hidden=[64] member; and the
+benchmark's paper cell for the local-round kernel: N = 100 nodes of the
+paper MLP, 9 local steps of batch 32 a round, from a 60,000-image bank.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every test worker
@@ -28,7 +30,7 @@ from repro.configs.paper_mlp import CONFIG as PAPER_MLP
 from repro.core import sparse
 from repro.core import topology as T
 from repro.experiments import presets
-from repro.kernels import ops
+from repro.kernels import local_round, ops
 from repro.models.mlp import init_mlp
 
 
@@ -99,4 +101,30 @@ def test_blocked_ell_compiles_at_large_n_width(one_chip):
         (bell.idx.shape, jnp.int32), (bell.val.shape, jnp.float32),
         ((g.num_nodes, p), jnp.float32), sharding=one_chip,
     )
+    assert "tpu_custom_call" in text
+
+
+def test_local_round_compiles_at_paper_mlp_width(one_chip):
+    n, steps, batch = PAPER_MLP.num_nodes, 9, 32
+
+    def stacked(k):
+        return jax.vmap(lambda k: init_mlp(
+            k, in_dim=PAPER_MLP.in_dim, hidden=PAPER_MLP.hidden,
+            num_classes=PAPER_MLP.num_classes,
+        ))(jax.random.split(k, n))
+
+    def sds(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    state = jax.tree.map(sds, jax.eval_shape(stacked, jax.random.PRNGKey(0)))
+    x = sds(jax.ShapeDtypeStruct((60_000, PAPER_MLP.in_dim), jnp.float32))
+    rows = sds(jax.ShapeDtypeStruct((n, steps, batch), jnp.int32))
+    assert local_round.fits(state, state, x, steps=steps, batch=batch) is False  # not at highest
+    with jax.default_matmul_precision("highest"):
+        assert local_round.fits(state, state, x, steps=steps, batch=batch)
+        text = jax.jit(
+            lambda x, r, l, p, m: local_round.local_round(
+                x, r, l, p, m, lr=0.05, mu=0.9, interpret=False),
+            donate_argnums=(3, 4),
+        ).lower(x, rows, rows, state, state).compile().as_text()
     assert "tpu_custom_call" in text
